@@ -73,6 +73,9 @@ def main(argv=None):
                     help="paper-scale sizes (slow); default is quick mode")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     failures = []
     for name, mod in SUITE:

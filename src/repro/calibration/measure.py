@@ -7,8 +7,9 @@ cell:
   public :mod:`repro.kernels` ``ops`` wrappers on the attached
   accelerator (warmup + median-of-k ``time.perf_counter``), and adds the
   analytic weight-streaming and launch-overhead terms the attention
-  kernels alone cannot see.  Only meaningful on a real accelerator;
-  interpret-mode timings measure the Python emulator, not silicon.
+  kernels alone cannot see, at the attached chip's peak rates
+  (:data:`repro.launch.mesh.DEVICE_PEAKS`; an unknown ``device_kind`` is
+  an error).  The kernels run compiled, so this backend needs a TPU.
 * ``"roofline"`` -- fully deterministic closed-form fallback: per-
   iteration FLOPs and HBM bytes from the :class:`ModelConfig` shape math
   (the same physics as ``launch/roofline.py``) against
@@ -27,7 +28,7 @@ import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-from repro.launch.mesh import v5e_constants
+from repro.launch.mesh import device_peaks, v5e_constants
 from repro.telemetry.timing import timeit_median
 
 from .grid import CalibrationGrid, GridCell
@@ -35,6 +36,7 @@ from .grid import CalibrationGrid, GridCell
 __all__ = [
     "DEFAULT_OVERHEAD_S",
     "Sample",
+    "backend_peaks",
     "collect_samples",
     "iteration_costs",
     "roofline_tau",
@@ -137,7 +139,7 @@ def _cell_tokens(cell: GridCell) -> int:
 
 
 # ---------------------------------------------------------------- kernels
-def _time_kernels_cell(cfg, cell: GridCell, *, reps: int) -> float:
+def _time_kernels_cell(cfg, cell: GridCell, *, reps: int, hw: dict) -> float:
     """Accelerator path: Pallas attention kernels + analytic rest.
 
     The attention ops see the cell's exact (C, K) shapes; the dense
@@ -182,7 +184,6 @@ def _time_kernels_cell(cfg, cell: GridCell, *, reps: int) -> float:
         tau += timeit_median(run_prefill, reps=reps)
 
     # analytic weight-stream + launch terms (attention already measured)
-    hw = v5e_constants()
     from repro.models.model import active_param_count
     n_active = active_param_count(cfg)
     tau += (DEFAULT_OVERHEAD_S
@@ -194,11 +195,17 @@ def _time_kernels_cell(cfg, cell: GridCell, *, reps: int) -> float:
 def _resolve_backend(backend: str) -> str:
     if backend != "auto":
         return backend
-    try:
-        import jax
-        return "kernels" if jax.default_backend() == "tpu" else "roofline"
-    except Exception:
-        return "roofline"
+    import jax
+    return "kernels" if jax.default_backend() == "tpu" else "roofline"
+
+
+def backend_peaks(backend: str) -> dict:
+    """Peak rates a backend's analytic terms are charged against: the
+    roofline model's stated v5e, or the attached chip's table entry."""
+    if backend == "roofline":
+        return v5e_constants()
+    import jax
+    return device_peaks(jax.devices()[0].device_kind)
 
 
 def collect_samples(grid: CalibrationGrid, cfg, *, backend: str = "auto",
@@ -207,10 +214,11 @@ def collect_samples(grid: CalibrationGrid, cfg, *, backend: str = "auto",
     backend = _resolve_backend(backend)
     if backend not in ("kernels", "roofline"):
         raise ValueError(f"unknown backend {backend!r}")
+    hw = backend_peaks(backend)
     out: List[Sample] = []
     for cell in grid.cells():
         if backend == "kernels":
-            tau = _time_kernels_cell(cfg, cell, reps=reps)
+            tau = _time_kernels_cell(cfg, cell, reps=reps, hw=hw)
         else:
             tau = roofline_tau(cfg, tokens=_cell_tokens(cell),
                                kv_tokens=cell.kv)

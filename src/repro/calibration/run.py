@@ -15,12 +15,11 @@ import argparse
 from typing import Optional
 
 from repro.configs import ARCHS, get_config
-from repro.launch.mesh import v5e_constants
 
 from .artifact import CalibrationArtifact
 from .fit import fit_surfaces
 from .grid import CalibrationGrid
-from .measure import collect_samples
+from .measure import backend_peaks, collect_samples
 
 __all__ = ["calibrate"]
 
@@ -41,7 +40,8 @@ def calibrate(arch: str = "qwen2-0.5b", *,
         samples=tuple(samples),
         mix=fits["mix"],
         solo=fits["solo"],
-        hw={k: float(v) for k, v in v5e_constants().items()},
+        hw={k: float(v)
+            for k, v in backend_peaks(samples[0].backend).items()},
         created=created,
     )
 

@@ -84,7 +84,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import prng_key
 from repro.telemetry.probes import (ctmc_probe_carry, extract_probes,
                                     resolve_probe_spec,
                                     wrap_ctmc_step_probes)
@@ -518,7 +517,7 @@ class UniformizedCTMC:
     # -- raw (device array) interface -------------------------------------
     def _key(self, seed):
         if isinstance(seed, (int, np.integer)):
-            return prng_key(int(seed))
+            return jax.random.PRNGKey(int(seed))
         return seed
 
     def run_raw(self, seed) -> dict:

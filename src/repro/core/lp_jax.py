@@ -27,9 +27,12 @@ budget of ``DEFAULT_ITERS = 60`` has a 2x margin.  Iterates freeze once
 converged (steps are masked), so extra budget costs FLOPs, not accuracy.
 
 Numerics: the KKT solves need double precision (normal equations square
-the condition number), so the entry points run inside the
-``repro.compat.enable_x64`` scope -- double precision is *local* to the
-solver and the process-global default dtype is untouched.  The
+the condition number), so the entry points run inside a scoped
+``jax.enable_x64`` -- double precision is *local* to the solver and the
+process-global default dtype is untouched.  The solve is placed on the
+host CPU device on every platform (:func:`solve_device`): the TPU
+compiler has no float64 LU decomposition, and one placement gives one
+code path and identical answers everywhere.  The
 standard-form data is Ruiz-equilibrated before iterating, which is what
 keeps the badly scaled planning rows (``theta ~ 3e-4`` next to
 ``mu_p ~ 1e2``) well conditioned.
@@ -50,10 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import enable_x64
-
 __all__ = ["LPBatchResult", "solve_lp_batch", "linprog_max_jax",
-           "DEFAULT_ITERS", "DEFAULT_TOL"]
+           "solve_device", "DEFAULT_ITERS", "DEFAULT_TOL"]
 
 DEFAULT_ITERS = 60  # fixed Newton-step budget (see module docstring)
 DEFAULT_TOL = 1e-9  # relative primal/dual/complementarity target
@@ -246,6 +247,18 @@ def _ipm_batch(c, A_ub, b_ub, A_eq, b_eq, tol, iters):
     )(c, A_ub, b_ub, A_eq, b_eq)
 
 
+def solve_device():
+    """The device every solve runs on: the host CPU.
+
+    The solver's contract is float64 (rel 1e-6 against the simplex
+    oracle, ``docs/PLANNING.md``), and the TPU compiler implements LU
+    decomposition in F32/C64 only.  Placing the solve on the CPU on
+    every platform, rather than retrying there after a failure, keeps one
+    code path with identical answers on every backend.
+    """
+    return jax.devices("cpu")[0]
+
+
 def _as_batch(a, shape, name):
     out = np.asarray(a, dtype=np.float64)
     if out.shape != shape:
@@ -286,10 +299,9 @@ def solve_lp_batch(
     b_ub = _as_batch(b_ub, (S, m_ub), "b_ub")
     A_eq = _as_batch(A_eq, (S, m_eq, n), "A_eq")
     b_eq = _as_batch(b_eq, (S, m_eq), "b_eq")
-    with enable_x64():
-        out = _ipm_batch(jnp.asarray(c), jnp.asarray(A_ub),
-                         jnp.asarray(b_ub), jnp.asarray(A_eq),
-                         jnp.asarray(b_eq), float(tol), int(iters))
+    with jax.enable_x64(True):
+        args = jax.device_put((c, A_ub, b_ub, A_eq, b_eq), solve_device())
+        out = _ipm_batch(*args, float(tol), int(iters))
         out = {k: np.asarray(v) for k, v in out.items()}
     return LPBatchResult(**out)
 

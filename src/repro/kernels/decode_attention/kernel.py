@@ -30,6 +30,7 @@ _NEG = -2.0e9
 def _kernel(kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
             m_ref, l_ref, acc_ref, *,
             scale, softcap, window, block_s, n_s_blocks):
+    b = pl.program_id(0)
     isb = pl.program_id(2)
 
     @pl.when(isb == 0)
@@ -38,7 +39,7 @@ def _kernel(kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_len = kvlen_ref[0]
+    kv_len = kvlen_ref[b]
     s0 = isb * block_s
 
     @pl.when(s0 < kv_len)
@@ -54,18 +55,18 @@ def _kernel(kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         spos = s0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
         valid = spos < kv_len
         if window is not None:
-            qp = qpos_ref[0]
-            kp = kpos_ref[0][None, :]              # absolute ring positions
+            qp = qpos_ref[b]
+            kp = kpos_ref[0]                       # (1, block_s) ring positions
             valid &= qp - kp < window
             valid &= kp <= qp
         s = jnp.where(valid, s, _NEG)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]                        # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -73,19 +74,24 @@ def _kernel(kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     @pl.when(isb == n_s_blocks - 1)
     def _fin():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q, k_cache, v_cache, kv_len, *, window=None,
                             k_positions=None, q_positions=None,
                             attn_softcap=None, block_s=256,
                             interpret=False):
-    """q (B,1,H,D); caches (B,S,KV,D); kv_len (B,) -> (B,1,H,D)."""
+    """q (B,1,H,D); caches (B,S,KV,D); kv_len (B,) -> (B,1,H,D).
+
+    ``block_s`` must divide S (:func:`repro.kernels.decode_attention.ops.
+    decode_attention` pads S to a block multiple).  ``kv_len`` and the
+    query positions ride in SMEM as scalar-prefetch operands.
+    """
     B, _, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     block_s = min(block_s, S)
-    assert S % block_s == 0
+    assert S % block_s == 0, (S, block_s)
     ns = S // block_s
     scale = 1.0 / (D ** 0.5)
 
@@ -97,6 +103,8 @@ def decode_attention_pallas(q, k_cache, v_cache, kv_len, *, window=None,
                                        (B, S))
     if q_positions is None:
         q_positions = jnp.maximum(kv_len - 1, 0).astype(jnp.int32)
+    # (B, 1, S): a (1, block_s) tile per step satisfies the TPU tiling rule
+    kpos = k_positions.astype(jnp.int32).reshape(B, 1, S)
 
     kernel = functools.partial(
         _kernel, scale=scale, softcap=attn_softcap, window=window,
@@ -104,23 +112,27 @@ def decode_attention_pallas(q, k_cache, v_cache, kv_len, *, window=None,
 
     out = pl.pallas_call(
         kernel,
-        grid=(B, KV, ns),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),            # kv_len
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),            # q_pos
-            pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, D), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, block_s, D), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, block_s), lambda b, h, s: (b, s)),  # k_pos
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                 # kv_len, q_pos
+            grid=(B, KV, ns),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D), lambda b, h, s, *_: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_s, D),
+                             lambda b, h, s, *_: (b, h, s, 0)),
+                pl.BlockSpec((1, 1, block_s, D),
+                             lambda b, h, s, *_: (b, h, s, 0)),
+                pl.BlockSpec((1, 1, block_s), lambda b, h, s, *_: (b, 0, s)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D),
+                                   lambda b, h, s, *_: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),   # running max m
+                pltpu.VMEM((G, 1), jnp.float32),   # normalizer l
+                pltpu.VMEM((G, D), jnp.float32),   # output accumulator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q_positions.astype(jnp.int32),
-      qg, kt, vt, k_positions.astype(jnp.int32))
+      qg, kt, vt, kpos)
     return out.reshape(B, 1, H, D)

@@ -1,7 +1,8 @@
 """Jit'd public wrapper for the prefill flash-attention kernel.
 
-Pads head_dim to the TPU lane width (128) and sequence to the block size,
-dispatches to the Pallas kernel on TPU and to interpret mode elsewhere.
+Pads head_dim to the TPU lane width (128) and sequence to the block size.
+Interpret mode runs only when the caller asks for it; without a TPU, a
+compiled call fails in the Pallas lowering.
 """
 
 from __future__ import annotations
@@ -31,9 +32,7 @@ def _pad_to(x, axis, mult):
                                    "interpret"))
 def prefill_attention(q, k, v, *, causal=True, window=None, attn_softcap=None,
                       prefix_len=None, block_q=128, block_k=128,
-                      interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                      interpret=False):
     S = q.shape[1]
     block_q = min(block_q, max(8, S))
     block_k = min(block_k, max(8, S))

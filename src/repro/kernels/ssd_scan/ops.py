@@ -12,9 +12,7 @@ __all__ = ["ssd_scan"]
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x_dt, Bm, Cm, log_a, *, chunk=256, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def ssd_scan(x_dt, Bm, Cm, log_a, *, chunk=256, interpret=False):
     S = x_dt.shape[1]
     c = min(chunk, S)
     while S % c != 0:

@@ -16,10 +16,19 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.compat import make_mesh, shard_map
+__all__ = ["make_mesh", "make_production_mesh", "v5e_constants",
+           "device_peaks", "DEVICE_PEAKS", "cells_mesh", "shard_cells",
+           "shard_cells_fn"]
 
-__all__ = ["make_production_mesh", "v5e_constants", "cells_mesh",
-           "shard_cells", "shard_cells_fn"]
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (the sharding
+    propagation these meshes were written for; jax defaults to Explicit)."""
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -67,8 +76,8 @@ def shard_cells_fn(kernel, *, mesh):
     def vmapped(rep, bat):
         return jax.vmap(lambda b: kernel(rep, b))(bat)
 
-    sharded = shard_map(vmapped, mesh=mesh, in_specs=(P(), P("cells")),
-                        out_specs=P("cells"), check=False)
+    sharded = jax.shard_map(vmapped, mesh=mesh, in_specs=(P(), P("cells")),
+                            out_specs=P("cells"), check_vma=False)
     jitted = jax.jit(sharded)
 
     def fn(replicated, batched):
@@ -90,12 +99,32 @@ def shard_cells(kernel, replicated, batched, *, mesh):
     return shard_cells_fn(kernel, mesh=mesh)(replicated, batched)
 
 
-def v5e_constants() -> dict:
-    """TPU v5e per-chip hardware constants for the roofline terms."""
-    return {
+#: Per-chip peak rates keyed by ``jax.devices()[0].device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect
+#: over a 2D torus of 4 links per chip).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
         "peak_flops_bf16": 197e12,  # FLOP/s
         "hbm_bw": 819e9,            # B/s
         "ici_link_bw": 50e9,        # B/s per link (~45-50 GB/s each way)
         "hbm_bytes": 16 * 1024**3,  # 16 GiB
         "ici_links": 4,             # 2D torus: 4 links per chip
-    }
+    },
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; unknown kinds raise."""
+    if device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}; add it to "
+            f"repro.launch.mesh.DEVICE_PEAKS with its source "
+            f"(known: {sorted(DEVICE_PEAKS)})")
+    return dict(DEVICE_PEAKS[device_kind])
+
+
+def v5e_constants() -> dict:
+    """TPU v5e per-chip constants: the deterministic roofline model's
+    stated hardware, whatever device is attached."""
+    return device_peaks("TPU v5 lite")

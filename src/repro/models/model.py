@@ -49,6 +49,7 @@ __all__ = [
     "forward_train",
     "forward_prefill",
     "forward_decode",
+    "greedy_generate",
     "loss_fn",
     "encoder_forward",
     "param_count",
@@ -464,11 +465,12 @@ def loss_fn(cfg: ModelConfig, params, tokens, labels, *, prefix_embeds=None,
 
 def forward_prefill(cfg: ModelConfig, params, tokens, positions, caches, *,
                     prefix_embeds=None, enc_frames=None, unroll=False,
-                    kernel_impl="xla", continuation=False):
+                    kernel_impl="xla", continuation=False, last_index=None):
     """Prefill a chunk; returns (last-position logits, new caches).
 
     positions: (B, S) absolute positions of ``tokens`` (supports chunked /
-    continued prefill).
+    continued prefill).  ``last_index`` (may be traced) picks the token
+    whose logits are returned when the chunk ends in padding.
     """
     enc_out = None
     if cfg.encoder is not None:
@@ -483,8 +485,12 @@ def forward_prefill(cfg: ModelConfig, params, tokens, positions, caches, *,
         cfg, params, x, positions=positions, mode="prefill", caches=caches,
         prefix_len=prefix_len, enc_out=enc_out, unroll=unroll,
         kernel_impl=kernel_impl, continuation=continuation)
-    logits = _logits(cfg, params, x[:, -1:])
-    return logits, new_caches
+    if last_index is None:
+        x_last = x[:, -1:]
+    else:
+        x_last = jax.lax.dynamic_slice_in_dim(
+            x, (prefix_len or 0) + last_index, 1, axis=1)
+    return _logits(cfg, params, x_last), new_caches
 
 
 def forward_decode(cfg: ModelConfig, params, tokens, positions, caches, *,
@@ -500,6 +506,28 @@ def forward_decode(cfg: ModelConfig, params, tokens, positions, caches, *,
         cfg, params, x, positions=positions, mode="decode", caches=caches,
         prefix_len=None, enc_out=None, unroll=unroll)
     return _logits(cfg, params, x), new_caches
+
+
+def greedy_generate(cfg: ModelConfig, params, prompt, n_tokens: int, *,
+                    max_len: int) -> list:
+    """Plain greedy generation for one request: prefill the whole prompt
+    at batch 1, then decode one token at a time.
+
+    The reference the served path is checked against: no slots, chunks,
+    masking or KV migration.  Returns the ``n_tokens`` greedy token ids.
+    """
+    P = len(prompt)
+    prefill = jax.jit(lambda p, t, pos, c: forward_prefill(cfg, p, t, pos, c))
+    decode = jax.jit(lambda p, t, pos, c: forward_decode(cfg, p, t, pos, c))
+    caches = init_cache(cfg, 1, max_len, jnp.dtype(cfg.param_dtype))
+    logits, caches = prefill(params, jnp.asarray(prompt, jnp.int32)[None],
+                             jnp.arange(P, dtype=jnp.int32)[None], caches)
+    out = [int(jnp.argmax(logits[0, -1]))]
+    for i in range(n_tokens - 1):
+        logits, caches = decode(params, jnp.full((1, 1), out[-1], jnp.int32),
+                                jnp.full((1,), P + i, jnp.int32), caches)
+        out.append(int(jnp.argmax(logits[0, -1])))
+    return out
 
 
 def init_model(cfg: ModelConfig, key, dtype=jnp.float32):

@@ -28,7 +28,7 @@ from repro.core.policies import OccupancyGate
 from repro.core.types import Pricing, ServicePrimitives, WorkloadClass
 from repro.models.config import ModelConfig
 
-from .engine import ServerEngine, SlotRequest
+from .engine import ServerEngine, SlotRequest, server_programs
 
 __all__ = ["RealCluster", "ClusterMetrics"]
 
@@ -89,10 +89,15 @@ class RealCluster:
         self.view = _View(self)
         M = plan.mixed_servers(n_servers)
         self.groups = ["mixed" if s < M else "solo" for s in range(n_servers)]
+        # every server is a replica on the default device, and all of
+        # them run one compiled decode/mixed pair
+        programs = server_programs(cfg, prim.chunk)
         self.engines = [
-            ServerEngine(cfg, params, prim=prim, max_len=max_len, seed=seed + s)
+            ServerEngine(cfg, params, prim=prim, max_len=max_len,
+                         seed=seed + s, programs=programs)
             for s in range(n_servers)
         ]
+        self.completed: list[SlotRequest] = []  # in completion order
         self.prefill_q: list[deque] = [deque() for _ in range(self.I)]
         self.decode_buf: deque = deque()  # (req, sub_cache, meta)
         self.X = np.zeros(self.I)
@@ -148,11 +153,17 @@ class RealCluster:
 
     # ----------------------------------------------------------------- run
     def run(self, requests, horizon: float) -> ClusterMetrics:
-        """``requests``: iterable of (t_arrival, cls, prompt_tokens, D)."""
+        """``requests``: iterable of (t_arrival, cls, prompt_tokens, D).
+
+        Idle servers stop polling once every request has completed, so
+        the run ends at the last completion (or at ``horizon``).
+        """
         heap = []
         ctr = itertools.count()
+        outstanding = 0
         for (t, cls, toks, D) in requests:
             heapq.heappush(heap, (t, next(ctr), "arrival", (cls, toks, D)))
+            outstanding += 1
         for sid in range(len(self.engines)):
             heapq.heappush(heap, (0.0, next(ctr), "iter", sid))
         now = 0.0
@@ -172,6 +183,8 @@ class RealCluster:
                 sid = payload
                 eng = self.engines[sid]
                 if not eng.has_prefill and eng.n_decoding == 0:
+                    if outstanding == 0:
+                        continue
                     # idle; poll again shortly (cheap virtual-time tick)
                     self._admit_prefills()
                     if eng.has_prefill or eng.n_decoding:
@@ -183,19 +196,22 @@ class RealCluster:
                     continue
                 res = eng.step()
                 for req in res["completed"]:
+                    outstanding -= 1
+                    self.completed.append(req)
                     self.metrics.completions += 1
                     self.metrics.per_class_completions[req.cls] = (
                         self.metrics.per_class_completions.get(req.cls, 0) + 1)
                     self.metrics.revenue += self.pricing.bundled_reward(
                         self.classes[req.cls])
-                if res["prefill_done"] is not None:
-                    req = res["prefill_done"]
+                req = res["prefill_done"]
+                if req is not None:
                     self.X[req.cls] -= 1
-                    # extract the prefilled KV and route via the buffer
-                    r2, sub, meta = eng.extract_slot(res["prefill_slot"])
-                    assert r2 is req
-                    self.decode_buf.append((req, sub, meta, sid))
-                    self._dispatch_decodes()
+                    if req.tokens_out < req.decode_len:
+                        # extract the prefilled KV and route via the buffer
+                        r2, sub, meta = eng.extract_slot(res["prefill_slot"])
+                        assert r2 is req
+                        self.decode_buf.append((req, sub, meta, sid))
+                        self._dispatch_decodes()
                 self._admit_prefills()
                 heapq.heappush(
                     heap, (now + max(res["tau"], 1e-9), next(ctr), "iter", sid))

@@ -25,10 +25,9 @@ import numpy as np
 from repro.core.types import ServicePrimitives
 from repro.models.config import ModelConfig
 
-from .steps import (init_server_state, make_decode_step, make_mixed_step,
-                    make_prefill_step)
+from .steps import init_server_state, make_decode_step, make_mixed_step
 
-__all__ = ["SlotRequest", "ServerEngine"]
+__all__ = ["SlotRequest", "ServerEngine", "server_programs"]
 
 
 @dataclass
@@ -43,18 +42,30 @@ class SlotRequest:
     out_tokens: list = field(default_factory=list)
 
 
+def server_programs(cfg: ModelConfig, chunk: int) -> tuple:
+    """The jitted ``(decode_step, mixed_step)`` pair a server runs.
+
+    Servers of one model and chunk size share one pair, so a cluster of
+    N servers compiles each program once.
+    """
+    return jax.jit(make_decode_step(cfg)), jax.jit(make_mixed_step(cfg, chunk))
+
+
 class ServerEngine:
     def __init__(self, cfg: ModelConfig, params, *, prim: ServicePrimitives,
-                 max_len: int, dtype=jnp.float32, seed: int = 0):
+                 max_len: int, seed: int = 0, programs=None):
+        """The KV cache is held in ``cfg.param_dtype``; ``programs`` is a
+        :func:`server_programs` pair to share."""
         self.cfg = cfg
         self.params = params
         self.prim = prim
         self.B = prim.batch_cap
         self.chunk = prim.chunk
         self.max_len = max_len
-        self.state = init_server_state(cfg, self.B, max_len, dtype)
-        self._decode = jax.jit(make_decode_step(cfg))
-        self._mixed = jax.jit(make_mixed_step(cfg, self.chunk))
+        self.state = init_server_state(cfg, self.B, max_len,
+                                       jnp.dtype(cfg.param_dtype))
+        self._decode, self._mixed = (programs if programs is not None
+                                     else server_programs(cfg, self.chunk))
         self.slots: list[Optional[SlotRequest]] = [None] * self.B
         # host-side prefill progress (one prefill at a time, paper Section 2)
         self.prefill: Optional[tuple[SlotRequest, np.ndarray, int]] = None
@@ -135,18 +146,28 @@ class ServerEngine:
             n = min(self.chunk, len(toks) - done)
             chunk = np.zeros((self.chunk,), np.int32)
             chunk[:n] = toks[done:done + n]
-            self.state, dec_tokens, _ = self._mixed(
+            self.state, dec_tokens, first = self._mixed(
                 self.params, self.state, self.prefill_slot,
-                jnp.asarray(chunk), jnp.full((1, 1), done, jnp.int32))
+                jnp.asarray(chunk), jnp.full((1, 1), done, jnp.int32), n)
             # fix the slot's length to true progress (chunk may be padded)
             slot = self.prefill_slot
             self.state["length"] = self.state["length"].at[slot].set(
                 done + n)
-            self.state["last_token"] = self.state["last_token"].at[slot].set(
-                int(toks[done + n - 1]))
             out["tau"] = self.prim.alpha + self.prim.beta * n
             self._account_decode(dec_tokens, skip=slot, out=out)
             if done + n >= len(toks):
+                # the prompt's last logits give the first output token,
+                # which the slot's first decode step then consumes
+                first = int(first)
+                self.state["last_token"] = self.state["last_token"].at[
+                    slot].set(first)
+                req.tokens_out += 1
+                req.out_tokens.append(first)
+                if req.tokens_out >= req.decode_len:
+                    out["completed"].append(req)
+                    self.state["length"] = self.state["length"].at[
+                        slot].set(0)
+                    self.slots[slot] = None
                 out["prefill_done"] = req
                 out["prefill_slot"] = slot
                 self.prefill = None

@@ -143,7 +143,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import prng_key
 from repro.core.ctmc_jax import _categorical
 from repro.core.policies import (FCFSGate, OccupancyGate, PolicySpec,
                                  PriorityRatioGate)
@@ -1108,11 +1107,11 @@ def _run_segment(params, key, carry, i0, budget, **statics):
 def _as_keys(keys):
     """Normalize one-or-many seed specs (ints or PRNG keys) to arrays."""
     if isinstance(keys, (list, tuple)):
-        return jnp.stack([prng_key(int(k))
+        return jnp.stack([jax.random.PRNGKey(int(k))
                           if isinstance(k, (int, np.integer)) else k
                           for k in keys])
     if isinstance(keys, (int, np.integer)):
-        return prng_key(int(keys))
+        return jax.random.PRNGKey(int(keys))
     return keys
 
 
@@ -1362,7 +1361,7 @@ class ClusterEngineJAX:
     # -- raw (device array) interface -------------------------------------
     def _key(self, seed):
         if isinstance(seed, (int, np.integer)):
-            return prng_key(int(seed))
+            return jax.random.PRNGKey(int(seed))
         return seed
 
     @property

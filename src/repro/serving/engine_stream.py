@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import prng_key
 from repro.core.policies import PolicySpec
 from repro.core.types import WorkloadClass
 from repro.data.traces import (TraceTensors, TraceValidationError,
@@ -290,8 +289,8 @@ class StreamingEngineJAX:
         }
         acc = {k: 0.0 for k in ("ret", "done", "ttft_sum", "ttft_n",
                                 "tpot_sum", "tpot_n")}
-        key = prng_key(int(seed)) if isinstance(seed, (int, np.integer)) \
-            else seed
+        key = (jax.random.PRNGKey(int(seed))
+               if isinstance(seed, (int, np.integer)) else seed)
         h_eff = jnp.asarray(self.h_eff, dt)
         i = jnp.zeros((), jnp.int32)
         budget = 0

@@ -53,12 +53,13 @@ def make_prefill_step(cfg: ModelConfig, *, kernel_impl: str = "xla",
     over the cached context) -- the engine's mixed iterations use it.
     """
 
-    def prefill_step(params, caches, tokens, positions, *, enc_frames=None,
-                     prefix_embeds=None):
+    def prefill_step(params, caches, tokens, positions, *, last_index=None,
+                     enc_frames=None, prefix_embeds=None):
         logits, caches = M.forward_prefill(
             cfg, params, tokens, positions, caches,
             enc_frames=enc_frames, prefix_embeds=prefix_embeds,
-            unroll=unroll, kernel_impl=kernel_impl, continuation=continuation)
+            unroll=unroll, kernel_impl=kernel_impl, continuation=continuation,
+            last_index=last_index)
         return caches, greedy_sample(logits)
 
     return prefill_step
@@ -105,8 +106,9 @@ def make_mixed_step(cfg: ModelConfig, chunk: int, *, unroll: bool = False):
     while decoding one token on every *other* active slot.
 
     The chunk runs at batch=1 on a cache slice of the slot-structured state;
-    decode masks out the prefilling slot.  Returns (state, decode_tokens,
-    chunk_last_logits_token).
+    decode masks out the prefilling slot.  ``n_valid`` counts the chunk's
+    real tokens (the rest is padding; default: all).  Returns (state,
+    decode_tokens, greedy token after the chunk's last real token).
     """
     pf = make_prefill_step(cfg, unroll=unroll, continuation=True)
     dec = make_decode_step(cfg, unroll=unroll)
@@ -123,12 +125,13 @@ def make_mixed_step(cfg: ModelConfig, chunk: int, *, unroll: bool = False):
             tree, sub)
 
     def mixed_step(params, state, p_slot, chunk_tokens, chunk_pos0,
-                   *, enc_frames=None, prefix_embeds=None):
+                   n_valid=chunk, *, enc_frames=None, prefix_embeds=None):
         # --- prefill chunk on the designated slot (batch of 1)
         sub_cache = slice_slot(state["caches"], p_slot)
         positions = chunk_pos0 + jnp.arange(chunk)[None, :]
         sub_cache, tok = pf(params, sub_cache, chunk_tokens[None, :],
-                            positions, enc_frames=enc_frames,
+                            positions, last_index=n_valid - 1,
+                            enc_frames=enc_frames,
                             prefix_embeds=prefix_embeds)
         caches = write_slot(state["caches"], sub_cache, p_slot)
 

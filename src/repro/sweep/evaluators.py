@@ -321,7 +321,7 @@ def _eval_ctmc_jax(ctx: MixContext, token: str, n: int, *,
     ``spec.extra["ctmc_jax"]``.
 
     ``x64=True`` runs the whole cell in double precision
-    (:func:`repro.compat.enable_x64` scoped around construction and the
+    (``jax.enable_x64`` scoped around construction and the
     scan).  Required at production cluster sizes: once the mean
     inter-event time ``1/(3 n lam)`` drops below the ULP of the float32
     clock (``eps(t) ~ t * 2**-23``), the clock stalls mid-horizon while
@@ -336,7 +336,8 @@ def _eval_ctmc_jax(ctx: MixContext, token: str, n: int, *,
     """
     import contextlib
 
-    from repro.compat import enable_x64
+    import jax
+
     from repro.core.ctmc_jax import UniformizedCTMC
 
     spec = ctx.spec
@@ -351,7 +352,7 @@ def _eval_ctmc_jax(ctx: MixContext, token: str, n: int, *,
     x64 = bool(kw.pop("x64", False))
     kw.setdefault("telemetry", spec.extra.get("telemetry"))
     policy = resolve_policy(token, ctx, n)
-    with enable_x64() if x64 else contextlib.nullcontext():
+    with jax.enable_x64(True) if x64 else contextlib.nullcontext():
         sim = UniformizedCTMC(ctx.classes, ctx.prim, ctx.pricing, policy,
                               n=n, horizon=spec.horizon, warmup=spec.warmup,
                               **kw)

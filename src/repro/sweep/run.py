@@ -184,7 +184,9 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="minimal 1x1x1 grid (CI smoke test)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     spec = build_spec(args)
     print(f"[sweep:{spec.name}] {spec.evaluator}: "
           f"{len(spec.policies)} policies x {len(spec.n_servers)} sizes x "

@@ -19,7 +19,7 @@ Three properties make the layer safe on arbitrary grids:
 
 * **Host-count-agnostic PRNG** -- every cell's key derives from its
   *grid coordinates* (``cell_seed_sequence`` -> ``cell_int_seed`` ->
-  ``prng_key``), never from its device placement, so 1 device and N
+  ``jax.random.PRNGKey``), never from its device placement, so 1 device and N
   devices draw identical randomness.
 * **Padded-cell masking** -- a ragged batch (``n_cells`` not a multiple
   of the mesh) is padded by repeating cell 0; the padded lanes compute
@@ -36,6 +36,7 @@ See ``docs/SHARDING.md`` for the mesh layout and the tiling math.
 
 from __future__ import annotations
 
+import warnings
 from dataclasses import dataclass
 from typing import Optional
 
@@ -48,6 +49,8 @@ __all__ = [
     "pad_batch",
     "run_sharded",
     "detected_devices",
+    "warn_once",
+    "reset_warn_once",
 ]
 
 # every way a batch engine can execute its cell batch; "vmap" is the
@@ -60,19 +63,40 @@ def detected_devices() -> int:
     return jax.device_count()
 
 
+# Process-wide once-per-kind warning guard; tests re-arm it with
+# ``reset_warn_once``.
+_warned_once: set = set()
+
+
+def warn_once(kind: str, message: str, *, stacklevel: int = 3) -> bool:
+    """Emit ``message`` as a RuntimeWarning the first time ``kind`` is seen.
+
+    Returns True if the warning fired, False if ``kind`` already warned
+    in this process.
+    """
+    if kind in _warned_once:
+        return False
+    _warned_once.add(kind)
+    warnings.warn(message, RuntimeWarning, stacklevel=stacklevel + 1)
+    return True
+
+
+def reset_warn_once(kind: Optional[str] = None) -> None:
+    """Re-arm the once-per-kind guard (all kinds when ``kind`` is None)."""
+    if kind is None:
+        _warned_once.clear()
+    else:
+        _warned_once.discard(kind)
+
+
 def _warn_serialized(n_devices: int) -> None:
     """Once per process: a shard_map placement that landed on one device
-    is a correct but serial run.  Shares the ``"shard-serial"`` guard
-    with the compat shard_map shim, so the condition warns exactly once
-    no matter which layer detects it first."""
-    from repro.compat import warn_once
-
+    is a correct but serial run."""
     warn_once(
         "shard-serial",
         f"placement='shard_map' is running on a 1-device mesh "
         f"({n_devices} device detected): results are exact but the "
-        f"batch is not partitioned -- force more host devices with "
-        f"XLA_FLAGS=--xla_force_host_platform_device_count=N",
+        f"batch is not partitioned across devices",
         stacklevel=4)
 
 

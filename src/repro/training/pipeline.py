@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pcast, shard_map
 
 __all__ = ["bubble_fraction", "make_pipeline_forward"]
 
@@ -52,8 +51,8 @@ def make_pipeline_forward(stage_fn, mesh, *, n_micro: int, axis: str = "pipe"):
         buf = jnp.zeros_like(x0)  # inter-stage register
         outs = jnp.zeros((n_micro,) + x0.shape, x0.dtype)
         # carries become device-varying inside the loop; mark them so
-        buf = pcast(buf, (axis,), to="varying")
-        outs = pcast(outs, (axis,), to="varying")
+        buf = jax.lax.pcast(buf, (axis,), to="varying")
+        outs = jax.lax.pcast(outs, (axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -80,7 +79,7 @@ def make_pipeline_forward(stage_fn, mesh, *, n_micro: int, axis: str = "pipe"):
         outs = jnp.where(sid == S - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -175,8 +175,6 @@ def generate_batch(scenarios: Sequence[Scenario], seeds: Sequence[int],
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import prng_key
-
     if not scenarios or not len(seeds):
         raise ValueError("need at least one scenario and one seed")
     H = float(horizon if horizon is not None
@@ -195,7 +193,7 @@ def generate_batch(scenarios: Sequence[Scenario], seeds: Sequence[int],
         R = int(need + 4.0 * np.sqrt(max(need, 1.0)) + 64)
     stacked = {k: jnp.stack([jnp.asarray(p[k]) for p in params])
                for k in params[0]}
-    keys = jnp.stack([prng_key(int(s)) for s in seeds])
+    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
     kernel = _make_kernel(int(R), int(T), H / T)
     fn = jax.jit(jax.vmap(jax.vmap(kernel, in_axes=(None, 0)),
                           in_axes=(0, None)))
@@ -238,8 +236,6 @@ class ScenarioStream:
         import jax
         import jax.numpy as jnp
 
-        from repro.compat import prng_key
-
         if chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
         self.scenario = scenario
@@ -279,7 +275,7 @@ class ScenarioStream:
         self._cv_p = par["cv_p"].astype(np.float64)
         self._cv_d = par["cv_d"].astype(np.float64)
         self._patience = par["patience"].astype(np.float64)
-        self._key = prng_key(int(seed))
+        self._key = jax.random.PRNGKey(int(seed))
         self._i0 = 0
         self._t = 0.0
         self._done = False

@@ -170,11 +170,18 @@ def test_ssd_ops_parity_at_calibration_chunks(S):
                                atol=1e-2, rtol=1e-2)
 
 
-def test_model_attention_pallas_path_matches_xla():
+def test_model_attention_pallas_path_matches_xla(monkeypatch):
     """attention_prefill(kernel_impl='pallas') == xla path."""
+    import functools
+
+    from repro.kernels.prefill_attention import ops as pf_ops
     from repro.models.attention import attention_prefill, attn_defs
     from repro.models.config import AttentionConfig
     from repro.models.params import init_params
+
+    # the model calls the kernel compiled; on this CPU run it interprets
+    monkeypatch.setattr(pf_ops, "prefill_attention", functools.partial(
+        pf_ops.prefill_attention, interpret=True))
 
     cfg = AttentionConfig(n_heads=4, n_kv_heads=2, head_dim=32)
     p = init_params(attn_defs(cfg, 64), jax.random.PRNGKey(0))

@@ -227,7 +227,7 @@ def _toy_kernel_case(n_cells):
 @pytest.mark.sim
 def test_run_sharded_matches_vmap_one_device():
     import jax
-    from repro import compat
+    from repro.sweep.sharded import reset_warn_once
 
     kernel, rep, batched = _toy_kernel_case(5)
     # the oracle is the JITTED vmap -- what the engines actually run
@@ -235,7 +235,7 @@ def test_run_sharded_matches_vmap_one_device():
     # always jit-vs-jit)
     oracle = jax.jit(jax.vmap(lambda k, x: kernel(rep, (k, x))))(*batched)
 
-    compat.reset_warn_once("shard-serial")
+    reset_warn_once("shard-serial")
     with pytest.warns(RuntimeWarning, match="1-device mesh"):
         raw, report = run_sharded(kernel, rep, batched, n_devices=1)
     assert report["serialized"] and report["n_devices"] == 1
@@ -244,8 +244,7 @@ def test_run_sharded_matches_vmap_one_device():
                                       np.asarray(oracle[k]))
 
     # the per-process dedupe: the "shard-serial" kind is spent, so a
-    # second serialized run (and the compat shim, which shares the kind)
-    # stays quiet instead of warning once per layer per call
+    # second serialized run stays quiet instead of warning once per call
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         run_sharded(kernel, rep, batched, n_devices=1)
@@ -274,9 +273,9 @@ def test_run_sharded_tiling_matches_vmap():
 def test_ctmc_jax_x64_extra():
     # extra["ctmc_jax"]["x64"] scopes the whole cell in double precision
     # (the gap study needs it: the float32 clock stalls at production n)
+    import jax
     import jax.numpy as jnp
 
-    from repro.compat import enable_x64
     from repro.core.ctmc_jax import UniformizedCTMC
     from repro.sweep.evaluators import MixContext, resolve_policy
     from repro.sweep.run import default_mix
@@ -287,7 +286,7 @@ def test_ctmc_jax_x64_extra():
                      n_seeds=2, mixes=(default_mix(),), horizon=3.0,
                      warmup=1.0, extra={"ctmc_jax": {"x64": True}})
     ctx = MixContext(default_mix(), spec)
-    with enable_x64():
+    with jax.enable_x64(True):
         sim = UniformizedCTMC(ctx.classes, ctx.prim, ctx.pricing,
                               resolve_policy("gate_and_route", ctx, 10),
                               n=10, horizon=3.0, warmup=1.0)

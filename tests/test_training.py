@@ -77,12 +77,11 @@ def test_compressed_psum_preserves_mean_with_feedback():
     # single-device shard_map over a size-1 axis still exercises the path
     mesh = jax.make_mesh((1,), ("data",))
     f = make_compressed_psum(mesh, "data")
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(size=(32,))
                           .astype(np.float32))}
     r = {"w": jnp.zeros((32,), jnp.float32)}
-    fn = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     total = jnp.zeros((32,))
     for _ in range(50):
         mean, r = fn(g, r)
