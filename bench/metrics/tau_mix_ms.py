@@ -1,0 +1,9 @@
+"""Device milliseconds per ``mixed_step`` execution (device trace)."""
+
+
+def read(rec):
+    tr = rec["trace"]
+    p = None if tr is None else tr["programs"].get("mixed_step")
+    if not p or not p["count"]:
+        return None
+    return 1e3 * p["device_s"] / p["count"]
