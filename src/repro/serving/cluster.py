@@ -11,6 +11,10 @@ while token streams are bit-exact.
 This is deliberately the main policy only; the policy zoo / baselines run
 in :mod:`repro.serving.engine_sim` (same scheduler semantics, calibrated
 compute), mirroring the paper's own simulator/hardware split.
+
+While a JAX profiler session records, ``run``, the gate (``admit``) and the
+decode router (``route``) leave ``serve.cluster.*`` spans on the
+profiler's clock (:mod:`repro.telemetry.spans`).
 """
 
 from __future__ import annotations
@@ -27,6 +31,7 @@ from repro.core.planning import PlanSolution
 from repro.core.policies import OccupancyGate
 from repro.core.types import Pricing, ServicePrimitives, WorkloadClass
 from repro.models.config import ModelConfig
+from repro.telemetry import spans
 
 from .engine import ServerEngine, SlotRequest, server_programs
 
@@ -94,7 +99,7 @@ class RealCluster:
         programs = server_programs(cfg, prim.chunk)
         self.engines = [
             ServerEngine(cfg, params, prim=prim, max_len=max_len,
-                         seed=seed + s, programs=programs)
+                         seed=seed + s, programs=programs, server=s)
             for s in range(n_servers)
         ]
         self.completed: list[SlotRequest] = []  # in completion order
@@ -107,20 +112,21 @@ class RealCluster:
 
     # --------------------------------------------------------------- admit
     def _admit_prefills(self):
-        for sid, eng in enumerate(self.engines):
-            if self.groups[sid] != "mixed" or eng.has_prefill:
-                continue
-            if not eng.free_slots():
-                continue
-            waiting = [i for i in range(self.I) if self.prefill_q[i]]
-            if not waiting:
-                return
-            i = self.gate.select(self.view, waiting)
-            if i is None:
-                return
-            _, req, toks = self.prefill_q[i].popleft()
-            eng.start_prefill(req, toks)
-            self.X[i] += 1
+        with spans.span("serve.cluster.admit"):
+            for sid, eng in enumerate(self.engines):
+                if self.groups[sid] != "mixed" or eng.has_prefill:
+                    continue
+                if not eng.free_slots():
+                    continue
+                waiting = [i for i in range(self.I) if self.prefill_q[i]]
+                if not waiting:
+                    return
+                i = self.gate.select(self.view, waiting)
+                if i is None:
+                    return
+                _, req, toks = self.prefill_q[i].popleft()
+                eng.start_prefill(req, toks)
+                self.X[i] += 1
 
     def _free_decode_capacity(self, sid: int) -> int:
         cap = (self.prim.batch_cap - 1 if self.groups[sid] == "mixed"
@@ -129,91 +135,100 @@ class RealCluster:
 
     def _dispatch_decodes(self):
         """Solo-first work-conserving placement with real KV injection."""
-        while self.decode_buf:
-            order = [s for s in range(len(self.engines))
-                     if self.groups[s] == "solo"]
-            order += [s for s in range(len(self.engines))
-                      if self.groups[s] == "mixed"]
-            placed = False
-            for sid in order:
-                eng = self.engines[sid]
-                if self._free_decode_capacity(sid) <= 0:
-                    continue
-                free = eng.free_slots()
-                if not free:
-                    continue
-                req, sub, meta, src = self.decode_buf.popleft()
-                eng.inject_slot(free[0], req, sub, meta)
-                if src != sid:
-                    self.metrics.migrations += 1
-                placed = True
-                break
-            if not placed:
-                return
+        with spans.span("serve.cluster.route"):
+            while self.decode_buf:
+                order = [s for s in range(len(self.engines))
+                         if self.groups[s] == "solo"]
+                order += [s for s in range(len(self.engines))
+                          if self.groups[s] == "mixed"]
+                placed = False
+                for sid in order:
+                    eng = self.engines[sid]
+                    if self._free_decode_capacity(sid) <= 0:
+                        continue
+                    free = eng.free_slots()
+                    if not free:
+                        continue
+                    req, sub, meta, src = self.decode_buf.popleft()
+                    eng.inject_slot(free[0], req, sub, meta)
+                    if src != sid:
+                        self.metrics.migrations += 1
+                    placed = True
+                    break
+                if not placed:
+                    return
 
     # ----------------------------------------------------------------- run
     def run(self, requests, horizon: float) -> ClusterMetrics:
         """``requests``: iterable of (t_arrival, cls, prompt_tokens, D).
 
         Idle servers stop polling once every request has completed, so
-        the run ends at the last completion (or at ``horizon``).
+        the run ends at the last completion (or at ``horizon``).  Its span
+        counts the heap events it handled (``events``).
         """
-        heap = []
-        ctr = itertools.count()
-        outstanding = 0
-        for (t, cls, toks, D) in requests:
-            heapq.heappush(heap, (t, next(ctr), "arrival", (cls, toks, D)))
-            outstanding += 1
-        for sid in range(len(self.engines)):
-            heapq.heappush(heap, (0.0, next(ctr), "iter", sid))
-        now = 0.0
-        while heap:
-            t, _, kind, payload = heapq.heappop(heap)
-            if t > horizon:
-                break
-            now = t
-            if kind == "arrival":
-                cls, toks, D = payload
-                req = SlotRequest(rid=next(self._rid), cls=cls,
-                                  prompt_len=len(toks), decode_len=D)
-                self.prefill_q[cls].append((t, req, np.asarray(toks)))
-                self.metrics.arrivals += 1
-                self._admit_prefills()
-            else:  # server iteration boundary
-                sid = payload
-                eng = self.engines[sid]
-                if not eng.has_prefill and eng.n_decoding == 0:
-                    if outstanding == 0:
-                        continue
-                    # idle; poll again shortly (cheap virtual-time tick)
+        with spans.span("serve.cluster.run"):
+            heap = []
+            ctr = itertools.count()
+            outstanding = 0
+            for (t, cls, toks, D) in requests:
+                heapq.heappush(heap, (t, next(ctr), "arrival",
+                                      (cls, toks, D)))
+                outstanding += 1
+            for sid in range(len(self.engines)):
+                heapq.heappush(heap, (0.0, next(ctr), "iter", sid))
+            now = 0.0
+            events = 0
+            while heap:
+                t, _, kind, payload = heapq.heappop(heap)
+                if t > horizon:
+                    break
+                now = t
+                events += 1
+                if kind == "arrival":
+                    cls, toks, D = payload
+                    req = SlotRequest(rid=next(self._rid), cls=cls,
+                                      prompt_len=len(toks), decode_len=D)
+                    self.prefill_q[cls].append((t, req, np.asarray(toks)))
+                    self.metrics.arrivals += 1
                     self._admit_prefills()
-                    if eng.has_prefill or eng.n_decoding:
-                        heapq.heappush(heap, (now, next(ctr), "iter", sid))
-                    else:
-                        heapq.heappush(
-                            heap, (now + self.prim.tau_solo, next(ctr),
-                                   "iter", sid))
-                    continue
-                res = eng.step()
-                for req in res["completed"]:
-                    outstanding -= 1
-                    self.completed.append(req)
-                    self.metrics.completions += 1
-                    self.metrics.per_class_completions[req.cls] = (
-                        self.metrics.per_class_completions.get(req.cls, 0) + 1)
-                    self.metrics.revenue += self.pricing.bundled_reward(
-                        self.classes[req.cls])
-                req = res["prefill_done"]
-                if req is not None:
-                    self.X[req.cls] -= 1
-                    if req.tokens_out < req.decode_len:
-                        # extract the prefilled KV and route via the buffer
-                        r2, sub, meta = eng.extract_slot(res["prefill_slot"])
-                        assert r2 is req
-                        self.decode_buf.append((req, sub, meta, sid))
-                        self._dispatch_decodes()
-                self._admit_prefills()
-                heapq.heappush(
-                    heap, (now + max(res["tau"], 1e-9), next(ctr), "iter", sid))
+                else:  # server iteration boundary
+                    sid = payload
+                    eng = self.engines[sid]
+                    if not eng.has_prefill and eng.n_decoding == 0:
+                        if outstanding == 0:
+                            continue
+                        # idle; poll again shortly (cheap virtual-time tick)
+                        self._admit_prefills()
+                        if eng.has_prefill or eng.n_decoding:
+                            heapq.heappush(heap,
+                                           (now, next(ctr), "iter", sid))
+                        else:
+                            heapq.heappush(
+                                heap, (now + self.prim.tau_solo, next(ctr),
+                                       "iter", sid))
+                        continue
+                    res = eng.step()
+                    for req in res["completed"]:
+                        outstanding -= 1
+                        self.completed.append(req)
+                        self.metrics.completions += 1
+                        per_class = self.metrics.per_class_completions
+                        per_class[req.cls] = per_class.get(req.cls, 0) + 1
+                        self.metrics.revenue += self.pricing.bundled_reward(
+                            self.classes[req.cls])
+                    req = res["prefill_done"]
+                    if req is not None:
+                        self.X[req.cls] -= 1
+                        if req.tokens_out < req.decode_len:
+                            # extract the prefilled KV, route via the buffer
+                            r2, sub, meta = eng.extract_slot(
+                                res["prefill_slot"])
+                            assert r2 is req
+                            self.decode_buf.append((req, sub, meta, sid))
+                            self._dispatch_decodes()
+                    self._admit_prefills()
+                    heapq.heappush(heap, (now + max(res["tau"], 1e-9),
+                                          next(ctr), "iter", sid))
+            spans.add("events", events)
         self.metrics.horizon = min(now, horizon)
         return self.metrics

@@ -1,6 +1,6 @@
 """On-device observability for the engine stack.
 
-Three layers (see ``docs/OBSERVABILITY.md``):
+Four layers (see ``docs/OBSERVABILITY.md``):
 
 * :mod:`repro.telemetry.probes` -- jit-compatible fixed-shape state
   probes threaded through the engine scan carries (time-binned
@@ -11,6 +11,8 @@ Three layers (see ``docs/OBSERVABILITY.md``):
   JSON export of request lifecycles and replan epochs.
 * :mod:`repro.telemetry.manifest` -- schema-versioned ``RunRecord``
   JSONL provenance for every artifact-producing entry point.
+* :mod:`repro.telemetry.spans` -- host spans and counters of the served
+  path, recorded while a JAX profiler session records, on its clock.
 
 ``python -m repro.telemetry`` renders trajectory/SLI reports and
 validates emitted trace/manifest files.
@@ -22,6 +24,7 @@ from .manifest import (MANIFEST_SCHEMA_VERSION, append_record,
 from .probes import (PROBES, ProbeSpec, PyProbes, extract_probes,
                      hist_attainment, hist_edges, hist_percentile,
                      resolve_probe_spec)
+from . import spans
 from .timing import timeit_median
 from .trace import (TRACE_SCHEMA_VERSION, lifecycle_events, replan_events,
                     trace_payload, validate_trace, write_trace)
@@ -44,6 +47,7 @@ __all__ = [
     "replan_events",
     "resolve_probe_spec",
     "run_record",
+    "spans",
     "timeit_median",
     "trace_payload",
     "validate_record",
