@@ -153,8 +153,8 @@ def cache_pspecs(cfg: ModelConfig, caches_abs, mesh, strategy: dict,
         ax[1] = batch_axis if (batch_axis and
                                leaf.shape[1] % bsize == 0) else None
         if name in ("k", "v", "xk", "xv"):
-            # (rep, B, S, KV, D)
-            if heads_ax and leaf.shape[3] % msize == 0:
+            # k, v: (rep, B, S, KV·D); xk, xv: (rep, B, S, KV, D)
+            if heads_ax and cfg.attn.n_kv_heads % msize == 0:
                 ax[3] = heads_ax
             elif seq_ax and leaf.shape[2] % msize == 0:
                 ax[2] = seq_ax

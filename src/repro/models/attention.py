@@ -37,6 +37,7 @@ __all__ = [
     "decode_attention",
     "attention_prefill",
     "attention_decode",
+    "cache_write_decode",
     "init_kv_cache",
 ]
 
@@ -146,20 +147,33 @@ def blockwise_attention(
     return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
 
-def decode_attention(q, k_cache, v_cache, *, kv_len, k_positions=None,
-                     window=None, attn_softcap=None, q_positions=None):
-    """Single-token decode: q (B,1,H,D) over cache (B,S,KV,D); kv_len (B,)."""
+def decode_attention(q, k_cache, v_cache, k_new, v_new, *, kv_len,
+                     k_positions=None, window=None, attn_softcap=None,
+                     q_positions=None):
+    """Single-token decode: q (B,1,H,D) over the cache (B,S,KV,D), whose
+    slots below kv_len (B,) hold earlier tokens, and over the token's own
+    k_new, v_new (B,KV,D), which the cache does not hold yet."""
     B, _, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     scale = 1.0 / np.sqrt(D)
     qg = q.reshape(B, KV, G, D)
+    # Both products contract over the cache's minor axis as it is stored,
+    # KV·D, so each layer's K and V are read in place, with no relayout:
+    # a head's query fills its KV head's D columns and zeros the rest.
+    eye = jnp.eye(KV, dtype=q.dtype)
+    q_rows = jnp.einsum("bkgd,kj->bkgjd", qg, eye).reshape(B, KV, G, KV * D)
     sc = jnp.einsum(
-        "bkgd,bskd->bkgs", qg, k_cache,
+        "bkgc,bsc->bkgs", q_rows, k_cache.reshape(B, S, KV * D),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    sn = jnp.einsum(
+        "bkgd,bkd->bkg", qg, k_new,
         preferred_element_type=jnp.float32,
     ) * scale
     if attn_softcap is not None:
         sc = softcap(sc, attn_softcap)
+        sn = softcap(sn, attn_softcap)
     pos = jnp.arange(S)
     valid = pos[None, :] < kv_len[:, None]  # (B,S)
     if window is not None and k_positions is not None and q_positions is not None:
@@ -167,9 +181,21 @@ def decode_attention(q, k_cache, v_cache, *, kv_len, k_positions=None,
         valid &= q_positions[:, None] - k_positions < window
         valid &= k_positions <= q_positions[:, None]
     sc = jnp.where(valid[:, None, None, :], sc, _NEG)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache)
-    return o.reshape(B, 1, H, D)
+    # softmax over the cache's scores and the token's own
+    m = jnp.maximum(sc.max(axis=-1), sn)
+    e = jnp.exp(sc - m[..., None])
+    en = jnp.exp(sn - m)
+    denom = e.sum(axis=-1) + en
+    p = (e / denom[..., None]).astype(v_cache.dtype)
+    pn = (en / denom).astype(v_cache.dtype)
+    o_rows = jnp.einsum(
+        "bkgs,bsc->bkgc", p, v_cache.reshape(B, S, KV * D),
+        preferred_element_type=jnp.float32,
+    ).reshape(B, KV, G, KV, D)
+    o = (jnp.einsum("bkgjd,kj->bkgd", o_rows, eye.astype(jnp.float32))
+         + jnp.einsum("bkg,bkd->bkgd", pn, v_new,
+                      preferred_element_type=jnp.float32))
+    return o.astype(v_cache.dtype).reshape(B, 1, H, D)
 
 
 # ------------------------------------------------------------------ caches
@@ -179,17 +205,20 @@ def init_kv_cache(batch, max_len, n_kv, head_dim, dtype, ring_window=None,
                   quant=False):
     """KV cache; ring-buffered when ``ring_window`` is set (local layers).
 
+    K and V are stored as (B, S, KV·D), a token's heads side by side on the
+    minor axis, so that writing one token touches one row of each and
+    needs no relayout of the cache; readers view them as (B, S, KV, D).
+
     ``quant=True`` stores K/V in int8 with per-(token, kv-head) fp16 scales
     (~2x less decode HBM traffic than bf16; the scale overhead is
     2/head_dim).  Quantisation happens in the cache writers; readers
     dequantise on load.
     """
     S = min(max_len, ring_window) if ring_window else max_len
+    kv_dtype = jnp.int8 if quant else dtype
     cache = {
-        "k": jnp.zeros((batch, S, n_kv, head_dim),
-                       jnp.int8 if quant else dtype),
-        "v": jnp.zeros((batch, S, n_kv, head_dim),
-                       jnp.int8 if quant else dtype),
+        "k": jnp.zeros((batch, S, n_kv * head_dim), kv_dtype),
+        "v": jnp.zeros((batch, S, n_kv * head_dim), kv_dtype),
         # absolute position of each slot (ring caches need it for masking)
         "pos": jnp.full((batch, S), -1, jnp.int32),
     }
@@ -213,58 +242,67 @@ def _dequantize_kv(q, scale, dtype):
             ).astype(dtype)
 
 
+def _cache_entries(k, v, quant):
+    """k, v (..., KV, D) -> the cache's entries for those tokens: K and V
+    as (..., KV·D), int8 with (..., KV) scales when ``quant``."""
+    flat = lambda a: a.reshape(a.shape[:-2] + (-1,))  # noqa: E731
+    if quant:
+        qk, sk = _quantize_kv(k)
+        qv, sv = _quantize_kv(v)
+        return {"k": flat(qk), "v": flat(qv), "k_s": sk, "v_s": sv}
+    return {"k": flat(k), "v": flat(v)}
+
+
+def cache_kv_arrays(entries, dtype, n_kv):
+    """Read (k, v) from a cache, or from entries of it, as (..., KV, D),
+    dequantised to ``dtype`` if int8-quantised."""
+    k, v = (entries[n].reshape(entries[n].shape[:-1] + (n_kv, -1))
+            for n in ("k", "v"))
+    if "k_s" in entries:
+        return (_dequantize_kv(k, entries["k_s"], dtype),
+                _dequantize_kv(v, entries["v_s"], dtype))
+    return k, v
+
+
 def cache_write_prefill(cache, k, v, positions):
     """Write a full prefill chunk at positions (B,S) (assumed in range).
 
     For ring caches only the last `ring` tokens land (modulo write); the
     inputs are sliced first so duplicate ring slots are never scattered.
     """
-    S_cache = cache["k"].shape[1]
+    S_cache = cache["pos"].shape[1]
     if k.shape[1] > S_cache:
         k = k[:, -S_cache:]
         v = v[:, -S_cache:]
         positions = positions[:, -S_cache:]
     idx = positions % S_cache
     b = jnp.arange(k.shape[0])[:, None]
-    out = {"pos": cache["pos"].at[b, idx].set(positions)}
-    if "k_s" in cache:
-        qk, sk = _quantize_kv(k)
-        qv, sv = _quantize_kv(v)
-        out["k"] = cache["k"].at[b, idx].set(qk)
-        out["v"] = cache["v"].at[b, idx].set(qv)
-        out["k_s"] = cache["k_s"].at[b, idx].set(sk)
-        out["v_s"] = cache["v_s"].at[b, idx].set(sv)
-    else:
-        out["k"] = cache["k"].at[b, idx].set(k)
-        out["v"] = cache["v"].at[b, idx].set(v)
-    return out
+    new = dict(_cache_entries(k, v, "k_s" in cache), pos=positions)
+    return {n: cache[n].at[b, idx].set(a) for n, a in new.items()}
 
 
-def cache_write_decode(cache, k, v, positions):
-    """Write one token at positions (B,); k,v (B,1,KV,D)."""
-    S_cache = cache["k"].shape[1]
-    idx = (positions % S_cache)[:, None]
-    b = jnp.arange(k.shape[0])[:, None]
-    out = {"pos": cache["pos"].at[b, idx].set(positions[:, None])}
-    if "k_s" in cache:
-        qk, sk = _quantize_kv(k)
-        qv, sv = _quantize_kv(v)
-        out["k"] = cache["k"].at[b, idx].set(qk)
-        out["v"] = cache["v"].at[b, idx].set(qv)
-        out["k_s"] = cache["k_s"].at[b, idx].set(sk)
-        out["v_s"] = cache["v_s"].at[b, idx].set(sv)
-    else:
-        out["k"] = cache["k"].at[b, idx].set(k)
-        out["v"] = cache["v"].at[b, idx].set(v)
-    return out
+def cache_write_decode(cache, new, positions, active=None):
+    """Write one token per slot into a layer-stacked cache, in place.
 
-
-def cache_kv_arrays(cache, dtype):
-    """Read (k, v) from a cache, dequantising if int8-quantised."""
-    if "k_s" in cache:
-        return (_dequantize_kv(cache["k"], cache["k_s"], dtype),
-                _dequantize_kv(cache["v"], cache["v_s"], dtype))
-    return cache["k"], cache["v"]
+    ``cache`` leaves are (L, B, S, ...); ``new`` holds the entries that
+    :func:`attention_decode` returned, stacked over the layers (L, B,
+    ...); positions (B,).  A slot whose ``active`` is False gets an index
+    past the cache's end, so its write is dropped and none of its bytes
+    change.  With the cache donated, the scatter updates its buffer.
+    """
+    L, B, S_cache = cache["pos"].shape
+    idx = positions % S_cache
+    if active is not None:
+        idx = jnp.where(active, idx, S_cache)
+    # one (layer, slot, index) triple per row written: only the minor axis
+    # is a window, so the scatter keeps the cache's layout
+    lay = jnp.arange(L)[:, None]
+    b = jnp.arange(B)[None, :]
+    new = dict(new, pos=jnp.broadcast_to(positions, (L, B)))
+    return dict(cache, **{
+        n: cache[n].at[lay, b, idx[None, :]].set(a, mode="drop",
+                                                  unique_indices=True)
+        for n, a in new.items()})
 
 
 # ------------------------------------------------------------ full blocks
@@ -303,7 +341,7 @@ def attention_prefill(cfg: AttentionConfig, p, x, positions, *, local: bool,
         new_cache = cache_write_prefill(cache, k, v, positions)
     if continuation:
         assert new_cache is not None, "continuation needs a cache"
-        kk, vv = cache_kv_arrays(new_cache, v.dtype)
+        kk, vv = cache_kv_arrays(new_cache, v.dtype, cfg.n_kv_heads)
         S_cache = kk.shape[1]
         kv_len = jnp.minimum(positions[:, -1] + 1, S_cache)
         out = blockwise_attention(
@@ -334,21 +372,28 @@ def attention_prefill(cfg: AttentionConfig, p, x, positions, *, local: bool,
 
 def attention_decode(cfg: AttentionConfig, p, x, positions, cache, *,
                      local: bool):
-    """One-token decode; positions (B,) = current index; updates cache."""
+    """One-token decode; positions (B,) = current index.
+
+    The cache is only read: the token attends over it and over its own K
+    and V.  Returns (out, entries), the token's cache entries, which
+    :func:`cache_write_decode` writes once the layer loop is done.
+    """
     q, k, v = _project_qkv(cfg, p, x)  # (B,1,·,D)
     if cfg.rope:
         sin, cos = rope_table(positions[:, None], cfg.head_dim, cfg.rope_theta)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
-    cache = cache_write_decode(cache, k, v, positions)
-    S_cache = cache["k"].shape[1]
-    kv_len = jnp.minimum(positions + 1, S_cache)
-    kk, vv = cache_kv_arrays(cache, v.dtype)
+    new = {n: a.astype(cache[n].dtype) for n, a in
+           _cache_entries(k[:, 0], v[:, 0], "k_s" in cache).items()}
+    # attend to the token as the cache will hold it
+    k_new, v_new = cache_kv_arrays(new, v.dtype, cfg.n_kv_heads)
+    kk, vv = cache_kv_arrays(cache, v.dtype, cfg.n_kv_heads)
+    kv_len = jnp.minimum(positions, kk.shape[1])
     out = decode_attention(
-        q, kk, vv, kv_len=kv_len,
+        q, kk, vv, k_new, v_new, kv_len=kv_len,
         k_positions=cache["pos"], q_positions=positions,
         window=cfg.window if local else None,
         attn_softcap=cfg.attn_softcap,
     )
     proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return proj, cache
+    return proj, new

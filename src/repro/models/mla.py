@@ -72,19 +72,15 @@ def _mla_q(x):
 
 
 def _mla_write(cache, b, pos2d, c_kv, k_rope):
+    """Write latents at (b, pos2d); positions past the end are dropped."""
     if "c_s" in cache:
         qc, sc = _mla_q(c_kv)
         qr, sr = _mla_q(k_rope)
-        return {
-            "c_kv": cache["c_kv"].at[b, pos2d].set(qc),
-            "k_rope": cache["k_rope"].at[b, pos2d].set(qr),
-            "c_s": cache["c_s"].at[b, pos2d].set(sc),
-            "r_s": cache["r_s"].at[b, pos2d].set(sr),
-        }
-    return {
-        "c_kv": cache["c_kv"].at[b, pos2d].set(c_kv),
-        "k_rope": cache["k_rope"].at[b, pos2d].set(k_rope),
-    }
+        new = {"c_kv": qc, "k_rope": qr, "c_s": sc, "r_s": sr}
+    else:
+        new = {"c_kv": c_kv, "k_rope": k_rope}
+    return {n: cache[n].at[b, pos2d].set(a, mode="drop")
+            for n, a in new.items()}
 
 
 def _mla_read(cache, dtype):
@@ -177,8 +173,11 @@ def mla_prefill(cfg: MLAConfig, p, x, positions, cache=None, block_q=512,
     return out, new_cache
 
 
-def mla_decode(cfg: MLAConfig, p, x, positions, cache):
-    """One-token absorbed decode over the latent cache; positions (B,)."""
+def mla_decode(cfg: MLAConfig, p, x, positions, cache, active=None):
+    """One-token absorbed decode over the latent cache; positions (B,).
+
+    A slot whose ``active`` is False writes nothing: its index lies past
+    the cache's end and the write is dropped."""
     B = x.shape[0]
     q_nope, q_rope = _queries(cfg, p, x, positions[:, None])
     c_new = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(x.dtype))
@@ -186,9 +185,10 @@ def mla_decode(cfg: MLAConfig, p, x, positions, cache):
     sin, cos = rope_table(positions[:, None], cfg.qk_rope_dim, cfg.rope_theta)
     k_new = apply_rope(k_new[:, :, None, :], sin, cos)[:, :, 0, :]
     b = jnp.arange(B)[:, None]
-    cache = _mla_write(cache, b, positions[:, None], c_new, k_new)
-    ckv_all, krope_all = _mla_read(cache, x.dtype)
     S = cache["c_kv"].shape[1]
+    write = positions if active is None else jnp.where(active, positions, S)
+    cache = _mla_write(cache, b, write[:, None], c_new, k_new)
+    ckv_all, krope_all = _mla_read(cache, x.dtype)
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"].astype(x.dtype))[:, 0]
     scale = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
     sc = (

@@ -33,6 +33,7 @@ from .attention import (
     attention_decode,
     attention_prefill,
     blockwise_attention,
+    cache_write_decode,
     init_kv_cache,
 )
 from .config import BlockSpec, ModelConfig, segment_layers
@@ -211,12 +212,31 @@ def _cross_attention(cfg: ModelConfig, p, x, xk, xv):
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
 
 
+def _keep_inactive(new, old, active):
+    """Recurrent state: slots whose ``active`` is False keep ``old``."""
+    if active is None:
+        return new
+    return jax.tree.map(
+        lambda n, o: jnp.where(active.reshape((-1,) + (1,) * (n.ndim - 1)),
+                               n, o), new, old)
+
+
 def _apply_block(cfg: ModelConfig, spec: BlockSpec, p, x, *, positions, mode,
                  cache, prefix_len, enc_out, kernel_impl="xla",
-                 continuation=False):
-    """One layer. mode: "train" | "prefill" | "decode"."""
+                 continuation=False, active=None):
+    """One layer. mode: "train" | "prefill" | "decode".
+
+    Returns (x, cache).  In decode mode the cache returned holds only what
+    the step changes: an attention layer's entries for the new token
+    (written by :func:`_commit_decode` after the layer loop), an MLA
+    layer's latent cache with the token written, a recurrent layer's
+    state; slots whose ``active`` is False change nothing.
+    """
     h = _apply_norm(cfg, p["ln1"], x)
-    new_cache = dict(cache) if cache is not None else None
+    if cache is None:
+        new_cache = None
+    else:
+        new_cache = {} if mode == "decode" else dict(cache)
     if spec.mixer in ("attn", "attn_local"):
         local = spec.mixer == "attn_local"
         kv_keys = ("k", "v", "pos") + (
@@ -241,7 +261,8 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, p, x, *, positions, mode,
         sub = ({k: cache[k] for k in mla_keys}
                if cache is not None else None)
         if mode == "decode":
-            out, nc = mla_decode(cfg.mla, p["mla"], h, positions, sub)
+            out, nc = mla_decode(cfg.mla, p["mla"], h, positions, sub,
+                                 active=active)
             new_cache.update(nc)
         else:
             out, nc = mla_prefill(cfg.mla, p["mla"], h, positions, cache=sub,
@@ -253,7 +274,7 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, p, x, *, positions, mode,
                if cache is not None else None)
         if mode == "decode":
             out, nc = ssm_decode(cfg.ssm, p["ssm"], h, sub)
-            new_cache.update(nc)
+            new_cache.update(_keep_inactive(nc, sub, active))
         else:
             out, nc = ssm_forward(cfg.ssm, p["ssm"], h, cache=sub)
             if nc is not None:
@@ -263,7 +284,7 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, p, x, *, positions, mode,
                if cache is not None else None)
         if mode == "decode":
             out, nc = rglru_decode(cfg.rglru, p["rec"], h, sub)
-            new_cache.update(nc)
+            new_cache.update(_keep_inactive(nc, sub, active))
         else:
             out, nc = rglru_forward(cfg.rglru, p["rec"], h, cache=sub)
             if nc is not None:
@@ -299,9 +320,23 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, p, x, *, positions, mode,
 # --------------------------------------------------------------- backbone
 
 
+def _commit_decode(block, seg_c, seg_new, positions, active):
+    """A segment's caches after a decode step: each attention layer's
+    token entries written into the stacked cache in place, every other
+    layer's changed leaves taken as they are."""
+    out = {}
+    for bi, spec in enumerate(block):
+        c, new = seg_c[f"b{bi}"], seg_new[f"b{bi}"]
+        if spec.mixer in ("attn", "attn_local"):
+            out[f"b{bi}"] = cache_write_decode(c, new, positions, active)
+        else:
+            out[f"b{bi}"] = dict(c, **new)
+    return out
+
+
 def _run_segments(cfg: ModelConfig, params, x, *, positions, mode, caches,
                   prefix_len, enc_out, unroll, kernel_impl="xla",
-                  remat=False, continuation=False):
+                  remat=False, continuation=False, active=None):
     segs = segment_layers(cfg.block_specs())
     new_caches = [] if caches is not None else None
     for si, (block, rep) in enumerate(segs):
@@ -315,7 +350,8 @@ def _run_segments(cfg: ModelConfig, params, x, *, positions, mode, caches,
                     cfg, spec, p_slice[f"b{bi}"], x, positions=positions,
                     mode=mode, cache=(c_slice[f"b{bi}"] if c_slice else None),
                     prefix_len=prefix_len, enc_out=enc_out,
-                    kernel_impl=kernel_impl, continuation=continuation)
+                    kernel_impl=kernel_impl, continuation=continuation,
+                    active=active)
                 if nc is not None:
                     nc[f"b{bi}"] = c
             return x, nc
@@ -326,30 +362,32 @@ def _run_segments(cfg: ModelConfig, params, x, *, positions, mode, caches,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             )
 
+        nc = None
         if unroll or rep == 1:
             ncs = []
             for r in range(rep):
                 p_r = jax.tree.map(lambda a: a[r], seg_p)
                 c_r = (jax.tree.map(lambda a: a[r], seg_c)
                        if seg_c is not None else None)
-                x, nc = body(x, p_r, c_r)
-                ncs.append(nc)
-            if new_caches is not None:
-                new_caches.append(jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *ncs))
+                x, nc_r = body(x, p_r, c_r)
+                ncs.append(nc_r)
+            if seg_c is not None:
+                nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
+        elif seg_c is None:
+            def step(carry, p_slice):
+                y, _ = body(carry, p_slice, None)
+                return y, ()
+            x, _ = jax.lax.scan(step, x, seg_p)
         else:
-            if seg_c is None:
-                def step(carry, p_slice):
-                    y, _ = body(carry, p_slice, None)
-                    return y, ()
-                x, _ = jax.lax.scan(step, x, seg_p)
-            else:
-                def step(carry, inp):
-                    p_slice, c_slice = inp
-                    y, nc = body(carry, p_slice, c_slice)
-                    return y, nc
-                x, nc = jax.lax.scan(step, x, (seg_p, seg_c))
-                new_caches.append(nc)
+            def step(carry, inp):
+                p_slice, c_slice = inp
+                y, nc = body(carry, p_slice, c_slice)
+                return y, nc
+            x, nc = jax.lax.scan(step, x, (seg_p, seg_c))
+        if seg_c is not None:
+            if mode == "decode":
+                nc = _commit_decode(block, seg_c, nc, positions, active)
+            new_caches.append(nc)
     return x, new_caches
 
 
@@ -494,8 +532,12 @@ def forward_prefill(cfg: ModelConfig, params, tokens, positions, caches, *,
 
 
 def forward_decode(cfg: ModelConfig, params, tokens, positions, caches, *,
-                   unroll=False):
-    """One-token decode. tokens (B, 1); positions (B,) current index."""
+                   active=None, unroll=False):
+    """One-token decode. tokens (B, 1); positions (B,) current index.
+
+    ``active`` (B,) bool, if given: slots where it is False still compute
+    but leave every byte of their caches as it was.
+    """
     x = params["embed"][tokens]
     if cfg.scale_embed:
         x = x * np.sqrt(cfg.d_model).astype(np.float32)
@@ -504,7 +546,7 @@ def forward_decode(cfg: ModelConfig, params, tokens, positions, caches, *,
     x = x.astype(jnp.bfloat16 if cfg.param_dtype == "bfloat16" else x.dtype)
     x, new_caches = _run_segments(
         cfg, params, x, positions=positions, mode="decode", caches=caches,
-        prefix_len=None, enc_out=None, unroll=unroll)
+        prefix_len=None, enc_out=None, unroll=unroll, active=active)
     return _logits(cfg, params, x), new_caches
 
 

@@ -53,9 +53,12 @@ def server_programs(cfg: ModelConfig, chunk: int) -> tuple:
     """The jitted ``(decode_step, mixed_step)`` pair a server runs.
 
     Servers of one model and chunk size share one pair, so a cluster of
-    N servers compiles each program once.
+    N servers compiles each program once.  Both take the server state
+    donated: each step writes its tokens into the state's KV cache in
+    place, and the state passed in is consumed.
     """
-    return jax.jit(make_decode_step(cfg)), jax.jit(make_mixed_step(cfg, chunk))
+    return (jax.jit(make_decode_step(cfg), donate_argnums=1),
+            jax.jit(make_mixed_step(cfg, chunk), donate_argnums=1))
 
 
 class ServerEngine:

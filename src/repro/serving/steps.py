@@ -71,26 +71,18 @@ def make_decode_step(cfg: ModelConfig, *, unroll: bool = False,
 
     With ``masked=True`` (the engine path) inactive slots still *compute*
     (static shapes) but never mutate their caches -- essential when a mixed
-    iteration is concurrently prefilling one of the slots.  The dry-run
-    lowers ``masked=False`` (all slots active), the pure decode iteration.
+    iteration is concurrently prefilling one of the slots.  Each active
+    slot's token is written into the cache in place when ``state`` is
+    donated.  The dry-run lowers ``masked=False`` (all slots active), the
+    pure decode iteration.
     """
 
-    def merge(new, old, act):
-        # cache leaves are (layer_rep, B, ...): batch is axis 1
-        def one(n, o):
-            m = act.reshape((1, -1) + (1,) * (n.ndim - 2))
-            return jnp.where(m, n, o)
-        return jax.tree.map(one, new, old)
-
     def decode_step(params, state):
-        tokens = state["last_token"][:, None]
-        positions = state["length"]
-        logits, caches = M.forward_decode(
-            cfg, params, tokens, positions, state["caches"], unroll=unroll)
-        nxt = greedy_sample(logits)
         act = state["active"]
-        if masked:
-            caches = merge(caches, state["caches"], act)
+        logits, caches = M.forward_decode(
+            cfg, params, state["last_token"][:, None], state["length"],
+            state["caches"], active=act if masked else None, unroll=unroll)
+        nxt = greedy_sample(logits)
         return {
             "caches": caches,
             "length": state["length"] + act.astype(jnp.int32),

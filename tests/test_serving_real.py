@@ -3,15 +3,16 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.core.planning import solve_bundled_lp
 from repro.core.types import Pricing, ServicePrimitives, WorkloadClass
 from repro.models import model as M
 from repro.serving.cluster import RealCluster
-from repro.serving.engine import ServerEngine, SlotRequest
+from repro.serving.engine import ServerEngine, SlotRequest, server_programs
 from repro.serving.steps import (init_server_state, make_decode_step,
-                                 make_mixed_step)
+                                 make_mixed_step, make_prefill_step)
 
 
 def _mk(arch="qwen2-0.5b"):
@@ -33,7 +34,6 @@ def test_mixed_step_prefill_isolation():
         toks = jax.random.randint(jax.random.PRNGKey(1), (B, 8), 2,
                                   cfg.vocab_size)
         pos = jnp.broadcast_to(jnp.arange(8)[None], (B, 8))
-        from repro.serving.steps import make_prefill_step
         pf = make_prefill_step(cfg)
         caches, nxt = pf(params, st["caches"], toks, pos)
         st = dict(st, caches=caches,
@@ -52,6 +52,45 @@ def test_mixed_step_prefill_isolation():
                                   np.asarray(s_mixed["last_token"][:2]))
     np.testing.assert_array_equal(np.asarray(s_solo["length"][:2]),
                                   np.asarray(s_mixed["length"][:2]))
+
+
+@pytest.mark.parametrize("arch,kv_quant", [
+    ("qwen2-0.5b", False), ("qwen2-0.5b", True),
+    ("gemma2-2b", False),          # local layers' ring caches
+    ("recurrentgemma-2b", False),  # RG-LRU state beside local attention
+    ("mamba2-130m", False),        # SSM state
+    ("deepseek-v3-671b", False),   # MLA latent cache
+])
+def test_decode_leaves_inactive_slot_bytes_unchanged(arch, kv_quant):
+    """Under the donated ``server_programs`` pair, a decode step leaves
+    every byte of an inactive slot's cache (K, V, their scales and
+    ``pos``, or its recurrent state) as it was, and writes the active
+    slot's."""
+    cfg, params = _mk(arch)
+    cfg = cfg.replace(kv_quant=kv_quant)
+    B, max_len, C, P = 2, 64, 16, 8
+    decode, _ = server_programs(cfg, C)
+    st = init_server_state(cfg, B, max_len, jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(4), (B, P), 2,
+                              cfg.vocab_size)
+    pos = jnp.broadcast_to(jnp.arange(P)[None], (B, P))
+    caches, nxt = make_prefill_step(cfg)(params, st["caches"], toks, pos)
+    st = dict(st, caches=caches, length=jnp.full((B,), P, jnp.int32),
+              last_token=nxt, active=jnp.array([False, True]))
+    before = jax.tree.map(np.array, st["caches"])  # copies, not views
+    new, _ = decode(params, st)
+    assert jax.tree.leaves(st["caches"])[0].is_deleted()  # donated
+    after = jax.tree.map(np.array, new["caches"])
+    names = {p[-1].key for p, _ in jax.tree_util.tree_leaves_with_path(
+        before)}
+    if kv_quant:
+        assert {"k", "v", "k_s", "v_s", "pos"} <= names
+    for (path, b), a in zip(jax.tree_util.tree_leaves_with_path(before),
+                            jax.tree.leaves(after)):
+        # leaves are (layer, slot, ...): slot 0 is inactive, 1 active
+        assert b[:, 0].tobytes() == a[:, 0].tobytes(), path
+        assert b[:, 1].tobytes() != a[:, 1].tobytes(), path
+    np.testing.assert_array_equal(np.asarray(new["length"]), [P, P + 1])
 
 
 def test_kv_migration_preserves_tokens():

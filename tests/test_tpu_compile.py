@@ -9,6 +9,7 @@ import: only one process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +22,8 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.prefill_attention.ops import prefill_attention
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.models import model as M
-from repro.serving.steps import (init_server_state, make_decode_step,
-                                 make_mixed_step)
+from repro.serving.engine import server_programs
+from repro.serving.steps import init_server_state
 
 
 @pytest.fixture(scope="module")
@@ -147,22 +148,78 @@ def _served_shapes(cfg, B, max_len, sharding):
     return place(params), place(state)
 
 
+_SERVED: dict = {}
+
+
+def _served_program(step, sharding):
+    """qwen2-0.5b's served program as ``server_programs`` builds it, in
+    bf16 at published width, 16 slots of 2048 tokens; the layers are
+    scanned, so two of them stand for 24.  Returns (compiled, state
+    shapes); compiled once per step."""
+    if step not in _SERVED:
+        cfg = QWEN.replace(n_layers=2)
+        B, max_len, chunk = 16, 2048, 256
+        params, state = _served_shapes(cfg, B, max_len, sharding)
+        decode, mixed = server_programs(cfg, chunk)
+        i32 = jnp.int32
+        if step == "decode":
+            lowered = decode.lower(params, state)
+        else:
+            lowered = mixed.lower(
+                params, state, _spec((), i32, sharding),
+                _spec((chunk,), i32, sharding), _spec((1, 1), i32, sharding),
+                _spec((), i32, sharding))
+        _SERVED[step] = (lowered.compile(), state)
+    return _SERVED[step]
+
+
 @pytest.mark.parametrize("step", ["decode", "mixed"])
 def test_served_steps_compile_at_published_width(one_chip, step):
-    """qwen2-0.5b's served programs in bf16 at published width, 16 slots
-    of 2048 tokens; the layers are scanned, so two of them stand for 24."""
-    cfg = QWEN.replace(n_layers=2)
-    B, max_len, chunk = 16, 2048, 256
-    params, state = _served_shapes(cfg, B, max_len, one_chip)
-    i32 = jnp.int32
-    if step == "decode":
-        lowered = jax.jit(make_decode_step(cfg)).lower(params, state)
-    else:
-        lowered = jax.jit(make_mixed_step(cfg, chunk)).lower(
-            params, state, _spec((), i32, one_chip),
-            _spec((chunk,), i32, one_chip), _spec((1, 1), i32, one_chip),
-            _spec((), i32, one_chip))
-    mem = lowered.compile().memory_analysis()
+    compiled, _ = _served_program(step, one_chip)
+    mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16 * 1024**3, used
     assert np.isfinite(used)
+
+
+#: ops that may produce an array of a whole K or V leaf's shape: in-place
+#: writes into the donated cache, the fusions around them, and plumbing
+_IN_PLACE_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                 "fusion", "scatter", "dynamic-update-slice"}
+
+
+def _ops_of_shape(hlo_text, dims):
+    """(opcode, name) of every instruction, fused ones included, whose
+    result has these dimensions."""
+    want = ",".join(map(str, dims))
+    found = []
+    for m in re.finditer(r"(%\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                         hlo_text):
+        if m.group(2) == want:
+            found.append((m.group(3), m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_served_steps_update_the_cache_in_place(one_chip, step):
+    """The donated state's KV cache is the program's output buffer, and no
+    op selects, copies or builds afresh an array of a whole stacked K or
+    V leaf: each step writes its new tokens into the cache where it is."""
+    compiled, state = _served_program(step, one_chip)
+    mem = compiled.memory_analysis()
+    leaves = jax.tree_util.tree_leaves_with_path(state["caches"])
+    cache_bytes = sum(a.size * a.dtype.itemsize for _, a in leaves)
+    assert mem.alias_size_in_bytes >= cache_bytes, (
+        mem.alias_size_in_bytes, cache_bytes)
+    kv = [a for path, a in leaves if path[-1].key in ("k", "v")]
+    assert kv
+    text = compiled.as_text()
+    for a in kv:
+        bad = [op for op in _ops_of_shape(text, a.shape)
+               if op[0] not in _IN_PLACE_OPS]
+        assert not bad, (a.shape, bad)
+    if step == "decode":
+        # the mixed step's chunk activations are larger by nature
+        layer_k = kv[0].size // kv[0].shape[0] * kv[0].dtype.itemsize
+        assert mem.temp_size_in_bytes < layer_k, (
+            mem.temp_size_in_bytes, layer_k)
